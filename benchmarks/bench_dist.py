@@ -438,9 +438,18 @@ def _bench_coldstart(store_dir, phase):
     from repro.core.mpo import build_mpo, compress_mpo
     from repro.core.mps import neel_states, product_state_mps
     from repro.core.siteops import spin_half_space
+    import jax
+
     from repro.core.sweep import DMRGEngine
     from repro.dist import cache_stats, persist
 
+    cache_hits = [0]
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            cache_hits[0] += 1
+
+    jax.monitoring.register_event_listener(on_event)
     n, m = COLD_N, COLD_M
     sp = spin_half_space()
     terms = heisenberg_j1j2_terms(n // 2, 2, 1.0, 0.5, cylinder=False)
@@ -489,6 +498,9 @@ def _bench_coldstart(store_dir, phase):
             for k in ("plan_cache", "decomp_plan_cache", "env_plan_cache")
         ),
         "store": st["plan_store"],
+        # persistent compilation-cache hits in this process (a cold process
+        # must see none: its cache directory starts empty)
+        "compile_cache_hits": cache_hits[0],
     }
 
 
@@ -528,6 +540,9 @@ def _run_coldstart():
     env = dict(os.environ)
     env.setdefault("JAX_ENABLE_X64", "1")
     with tempfile.TemporaryDirectory(prefix="bench_coldstart_") as store_dir:
+        # the children's compilation cache lives in the store directory, so
+        # the cold child starts with an empty one
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(store_dir, "xla")
         cold = _coldstart_subprocess(store_dir, "cold", env)
         primed = _coldstart_subprocess(store_dir, "primed", env)
     steady = primed["steady_s"]
@@ -548,6 +563,7 @@ def _run_coldstart():
         "cold_plan_builds": cold["plan_builds"],
         "primed_plan_builds": primed["plan_builds"],
         "energy_diff": abs(cold["energy"] - primed["energy"]),
+        "primed_compile_cache_hits": primed["compile_cache_hits"],
         "store_saves": cold["store"]["saves"],
         "store_export_saves": cold["store"]["export_saves"],
     }

@@ -79,6 +79,9 @@ def main(argv=None):
 
     from repro.core import run_dmrg
     from repro.core.models import electron_system, spin_system
+    from repro.dist import configure_compilation_cache
+
+    configure_compilation_cache()
 
     if args.system == "spins":
         space, terms = spin_system(args.lx, args.ly, j2=args.j2)
@@ -93,7 +96,9 @@ def main(argv=None):
             make_block_mesh(), mode="spmd" if args.spmd else "auto"
         )
 
-    schedule = [m for m in (8, 16, 32, 64, 128, 256) if m <= args.max_bond]
+    schedule = [min(8, args.max_bond)]
+    while schedule[-1] < args.max_bond:
+        schedule.append(min(2 * schedule[-1], args.max_bond))
     print(f"{args.system}: {args.lx}x{args.ly} cylinder, {n} sites, "
           f"algo={'spmd' if args.spmd else args.algo}, schedule={schedule}"
           + (f", mesh={dict(shard_policy.mesh.shape)}" if shard_policy else ""))

@@ -7,11 +7,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
 
 from ..dist import persist
+from ..dist.engine import ContractionEngine
 from ..dist.shard import BlockShardPolicy, make_block_mesh
 from .checkpoint import (
     CheckpointManager,
@@ -30,6 +31,9 @@ class DMRGResult:
     energy: float
     mps: MPS
     sweep_stats: List[SweepStats]
+    # the run's ContractionEngine.stats() ledger (plan caches, backend and
+    # stage counters, retries/degradations); None for bare contractors
+    engine_stats: Optional[Dict] = None
 
     @property
     def energies(self) -> List[float]:
@@ -211,4 +215,12 @@ def _run_dmrg_body(
                     f"m={m:6d} E={s.energy:+.10f} maxbond={s.max_bond} "
                     f"trunc={s.trunc_err:.2e} t={s.seconds:.2f}s"
                 )
-    return DMRGResult(energy=stats[-1].energy, mps=engine.mps, sweep_stats=stats)
+    engine_stats = (
+        engine.contract_fn.stats()
+        if isinstance(engine.contract_fn, ContractionEngine)
+        else None
+    )
+    return DMRGResult(
+        energy=stats[-1].energy, mps=engine.mps, sweep_stats=stats,
+        engine_stats=engine_stats,
+    )

@@ -45,7 +45,9 @@ def get_contractor(algo: str) -> Callable:
     if algo in ("list", "dense", "batched"):
         return ContractionEngine(backend=algo)
     if algo == "csr":
-        return ContractionEngine(backend="csr", interpret=True, use_kernel=True)
+        # compiled Pallas (a TPU target); interpret mode only where a caller
+        # builds the engine with interpret=True itself, as CPU tests do
+        return ContractionEngine(backend="csr", use_kernel=True)
     if algo == "csr_ref":
         return ContractionEngine(backend="csr", use_kernel=False)
     if algo in ("auto", "planned"):

@@ -229,7 +229,7 @@ class DMRGEngine:
                 return self.contract_fn.env_update_left(
                     A, T, W, mpo_padded=self._padded_mpo(j)
                 )
-            except Exception:
+            except (FaultInjected, NumericalHealthError):
                 # degradation ladder (DESIGN.md 3.8): fused core failed —
                 # recover on the seed three-contraction path, which matches
                 # it to <1e-10 block-for-block, and keep sweeping
@@ -245,7 +245,7 @@ class DMRGEngine:
                 return self.contract_fn.env_update_right(
                     B, T, W, mpo_padded=self._padded_mpo(j + 1)
                 )
-            except Exception:
+            except (FaultInjected, NumericalHealthError):
                 self.contract_fn.note_retry("env")
                 self.contract_fn.note_degradation("env_seed")
         return extend_right(B, T, W, self.contract_fn)

@@ -9,10 +9,11 @@ execution:
               the gather tables of the blockwise SVD — each derived once per
               block structure and cached by structural signature.
 - ``persist``: ``PlanStore`` — versioned on-disk persistence for the three
-              plan caches, the JAX persistent compilation cache and
-              ``jax.export``ed bucket cores, so a fresh process's first
-              sweep skips the plan/trace/compile pipeline (DESIGN.md
-              Sec. 3.9).
+              plan caches and
+              ``jax.export``ed bucket cores, plus the one rule that picks
+              the JAX persistent compilation cache directory, so a fresh
+              process's first sweep skips the plan/trace/compile pipeline
+              (DESIGN.md Sec. 3.9).
 - ``shard``:  ``BlockShardPolicy`` — places blocks on the 2-D ("row",
               "col") mesh: "spmd" mode pins tensors device-resident
               (replicated, uploaded once) for shard_map compute; "storage"
@@ -28,9 +29,9 @@ execution:
               same-shape GEMMs + segment-sum scatter) and the power-of-two
               sector padding that makes the jitted matvec compile once.
 - ``decomp``: ``DecompositionEngine`` — the blockwise truncated SVD executed
-              as one batched ``jnp.linalg.svd`` per padded shape bucket,
-              with a single host sync for the global truncation and an
-              optional randomized-SVD path.
+              as one batched SVD per padded shape bucket (host LAPACK
+              for float64), with a single host sync for the global
+              truncation and an optional randomized-SVD path.
 - ``envcore``: ``EnvironmentEngine`` — the left/right environment updates
               (and the startup right-to-left rebuild) executed as ONE fused
               jitted core per padded structure: the three chained
@@ -69,7 +70,7 @@ from .persist import (
     active_store,
     canonical_signature,
     deactivate_store,
-    enable_compilation_cache,
+    configure_compilation_cache,
     signature_digest,
     store_stats,
     using_store,
@@ -142,7 +143,7 @@ __all__ = [
     "active_store",
     "canonical_signature",
     "deactivate_store",
-    "enable_compilation_cache",
+    "configure_compilation_cache",
     "signature_digest",
     "store_stats",
     "using_store",

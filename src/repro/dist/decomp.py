@@ -17,7 +17,8 @@ plan/execute split of the contraction engine for that stage:
    core per bucketed structure: a single gather assembles each bucket's
    stacked ``[S, Rp, Cp]`` sector matrices straight from the flattened theta
    blocks (no per-block ``.at[].set()``), each bucket runs as one batched
-   ``jnp.linalg.svd``, padding singular values are masked to exact zero in
+   SVD (``bucket_svd``: host LAPACK for float64, the device's own SVD
+   below that), padding singular values are masked to exact zero in
    padded space, and the absorb scaling happens on device.  Only the
    (small) concatenated singular-value vector is synced to host — one sync
    per call instead of one per sector — where the global truncation picks
@@ -42,6 +43,7 @@ under ``method="auto"``.
 """
 from __future__ import annotations
 
+import threading
 import time
 from typing import Dict, Optional, Tuple
 
@@ -76,6 +78,68 @@ def _cache_exec(plan: DecompositionPlan, key, core):
         plan._exec.pop(next(iter(plan._exec)))
 
 
+_host_svd_lock = threading.Lock()
+_host_svd = {"calls": 0, "seconds": 0.0}
+
+
+def host_svd_stats() -> Dict:
+    """Process-wide ledger of the float64 host LAPACK callback: ``calls``
+    and ``seconds`` of host wall-clock spent inside it (the decomposition
+    stage's host-resident share; the rest of ``svd_seconds`` is device
+    gather/absorb work and the truncation sync)."""
+    with _host_svd_lock:
+        return dict(_host_svd)
+
+
+def _host_lapack_svd(x: np.ndarray):
+    """numpy's LAPACK gesdd on a stack of matrices; NaN where it fails.
+
+    Non-convergence (or non-finite input) fills the outputs with NaN, the
+    same contract as XLA's own LAPACK lowering, so the numerical-health
+    guard at the truncation sync sees it instead of a callback exception.
+    """
+    t0 = time.perf_counter()
+    x = np.asarray(x)
+    try:
+        u, s, vh = np.linalg.svd(x, full_matrices=False)
+    except np.linalg.LinAlgError:
+        k = min(x.shape[-2:])
+        u = np.full(x.shape[:-1] + (k,), np.nan, x.dtype)
+        s = np.full(x.shape[:-2] + (k,), np.nan, np.finfo(x.dtype).dtype)
+        vh = np.full(x.shape[:-2] + (k, x.shape[-1]), np.nan, x.dtype)
+    out = u.astype(x.dtype), s.astype(np.finfo(x.dtype).dtype), vh.astype(x.dtype)
+    with _host_svd_lock:
+        _host_svd["calls"] += 1
+        _host_svd["seconds"] += time.perf_counter() - t0
+    return out
+
+
+def bucket_svd(mats: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Thin SVD of stacked ``[..., R, C]`` matrices inside a traced core.
+
+    64-bit inputs factorize on host LAPACK through ``jax.pure_callback``:
+    a TPU has no float64 unit, and XLA's emulated float64 SVD (QDWH polar
+    decomposition + eigh) takes ~75 s to compile for one [8, 256, 256]
+    bucket and over 10 minutes at 1024x1024 on a v5e, once per bucket
+    shape.  The callback compiles in milliseconds and is the same gesdd
+    the CPU backend lowers ``jnp.linalg.svd`` to, so every platform runs
+    one float64 path.  32-bit inputs stay on the device's own SVD.
+    """
+    if jnp.finfo(mats.dtype).bits < 64:
+        return jnp.linalg.svd(mats, full_matrices=False)
+    *lead, r, c = mats.shape
+    k = min(r, c)
+    real = jnp.finfo(mats.dtype).dtype
+    shapes = (
+        jax.ShapeDtypeStruct((*lead, r, k), mats.dtype),
+        jax.ShapeDtypeStruct((*lead, k), real),
+        jax.ShapeDtypeStruct((*lead, k, c), mats.dtype),
+    )
+    return jax.pure_callback(
+        _host_lapack_svd, shapes, mats, vmap_method="broadcast_all"
+    )
+
+
 def _randomized_svd(
     mats: jax.Array, sketch: int, power_iters: int, seed: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -100,7 +164,7 @@ def _randomized_svd(
         Z, _ = jnp.linalg.qr(mats_h @ Q)                           # [S, cp, l]
         Q, _ = jnp.linalg.qr(mats @ Z)
     B = jnp.swapaxes(jnp.conj(Q), -1, -2) @ mats                   # [S, l, cp]
-    Ub, s, Vh = jnp.linalg.svd(B, full_matrices=False)
+    Ub, s, Vh = bucket_svd(B)
     return Q @ Ub, s, Vh
 
 
@@ -145,7 +209,7 @@ def svd_core_body(
                     mats, sketch, rsvd_power_iters, rsvd_seed + bi
                 )
             else:
-                U, s, Vh = jnp.linalg.svd(mats, full_matrices=False)
+                U, s, Vh = bucket_svd(mats)
             # padding rows/cols contribute ~eps junk values; zero them so
             # the host truncation only ever sees the K=min(R,C) real ones
             mask = jnp.arange(s.shape[-1])[None, :] < bucket.k_true[:, None]
@@ -388,14 +452,15 @@ class DecompositionEngine:
         equal to the squared Frobenius reconstruction error
         ``||theta - U·V||²`` when ``absorb`` is "left" or "right".
 
-        Robustness (DESIGN.md 3.8): a failed attempt — an exception out of
-        the batched SVD core (LAPACK non-convergence, an injected
-        ``decomp.svd_fail``) or non-finite singular values at the host sync
-        — retries down the documented ladder: randomized → exact batched SVD
+        Robustness (DESIGN.md 3.8): a failed attempt — an injected
+        ``decomp.svd_fail`` or non-finite singular values at the host sync
+        (LAPACK non-convergence comes back as NaN) — retries down the
+        documented ladder: randomized → exact batched SVD
         → the seed per-sector loop (``svd_split_unplanned``).  Each rung is
         counted in ``stats()['retries']`` / ``['degradations']``; if the
         final rung still yields non-finite values the input itself is
-        poisoned and ``NumericalHealthError`` propagates to the caller.
+        poisoned and ``NumericalHealthError`` propagates to the caller.  Any
+        other exception — a compile or lowering error — propagates as is.
         """
         if _is_tracing(theta):
             raise TypeError(
@@ -414,7 +479,7 @@ class DecompositionEngine:
                 return self._execute_planned(
                     plan, theta, max_bond, cutoff, absorb, methods, sketch
                 )
-            except Exception:
+            except (FaultInjected, NumericalHealthError):
                 self.retries += 1
                 if "rsvd" in methods:
                     # ladder rung 1: drop the randomized sketch, retry exact
@@ -424,7 +489,7 @@ class DecompositionEngine:
                             plan, theta, max_bond, cutoff, absorb,
                             ("svd",) * plan.num_buckets, sketch,
                         )
-                    except Exception:
+                    except (FaultInjected, NumericalHealthError):
                         pass
                 # ladder rung 2 (final): the seed per-sector loop
                 self.degradations["svd_unplanned"] += 1

@@ -31,14 +31,14 @@ bond dimensions reuse both the plans and the compiled matvec.
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..kernels.block_gemm.ops import block_sparse_matmul
 from ..tensor.block_csr import pack_blocks
-from ..tensor.blocksparse import BlockKey, BlockSparseTensor, contract
+from ..tensor.blocksparse import BlockKey, BlockSparseTensor
 from .batch import (
     execute_batched,
     execute_pairs,
@@ -57,15 +57,6 @@ from .shard import BlockShardPolicy
 # on small DMRG blocks the per-op dispatch dominates, which is exactly why the
 # paper's dense algorithm wins at small m (their Fig. 5 crossover).
 PAIR_OVERHEAD_FLOPS = 16384.0
-
-# Degradation ladder for a failed contraction backend (DESIGN.md 3.8): on an
-# exception the engine retries each rung BELOW the failed one in this order,
-# ending at the seed ``tensor.blocksparse.contract``.  Ordered fastest/most
-# specialized first, so a failure costs the least capable machinery it can.
-# "spmd" is only a valid rung under an spmd-mode policy (operands are then
-# mesh-resident replicated, so every lower rung still computes correctly).
-CONTRACTION_LADDER: Tuple[str, ...] = ("spmd", "csr", "batched", "dense", "list")
-
 
 class ContractionEngine:
     """Executes cached ContractionPlans through a pluggable backend.
@@ -162,17 +153,12 @@ class ContractionEngine:
         ):
             a, b = self.policy.replicated(a), self.policy.replicated(b)
         t0 = time.perf_counter()
-        try:
-            if backend in ("batched", "spmd"):
-                out = getattr(self, f"_execute_{backend}")(
-                    plan, a, b, a_mats=a_mats, b_mats=b_mats
-                )
-            else:
-                out = getattr(self, f"_execute_{backend}")(plan, a, b)
-        except Exception:
-            if _is_tracing(a) or _is_tracing(b):
-                raise  # mid-trace failure: the caller's eager fallback recovers
-            out = self._degraded_call(backend, plan, a, b, axes)
+        if backend in ("batched", "spmd"):
+            out = getattr(self, f"_execute_{backend}")(
+                plan, a, b, a_mats=a_mats, b_mats=b_mats
+            )
+        else:
+            out = getattr(self, f"_execute_{backend}")(plan, a, b)
         self.backend_seconds[backend] += time.perf_counter() - t0
         # spmd mode constrains output layout; storage mode leaves compute
         # results replicated — the sweep re-places what it actually stores
@@ -219,44 +205,6 @@ class ContractionEngine:
     @property
     def _spmd_mode(self) -> bool:
         return self.policy is not None and self.policy.mode == "spmd"
-
-    # ---------------------------------------------------- degradation ladder
-    def _degraded_call(
-        self,
-        failed: str,
-        plan: ContractionPlan,
-        a: BlockSparseTensor,
-        b: BlockSparseTensor,
-        axes: Axes,
-    ) -> BlockSparseTensor:
-        """Retry a failed backend down ``CONTRACTION_LADDER`` to the seed.
-
-        Every rung computes the same charge-conserving contraction (the
-        backend-equality guarantee), so recovery changes wall time, never
-        values.  The final rung is the seed ``tensor.blocksparse.contract``
-        — plan-free, engine-free, the code path the whole dist layer is
-        tested against.  Only reached eagerly; mid-trace failures re-raise.
-        """
-        self.note_retry("contraction")
-        start = (
-            CONTRACTION_LADDER.index(failed) + 1
-            if failed in CONTRACTION_LADDER
-            else 0
-        )
-        for rung in CONTRACTION_LADDER[start:]:
-            if rung == "csr" and not self.allow_csr:
-                continue
-            if rung == "spmd" and not self._spmd_mode:
-                continue
-            try:
-                out = getattr(self, f"_execute_{rung}")(plan, a, b)
-            except Exception:
-                continue
-            self.note_degradation(f"contraction_{rung}")
-            return out
-        out = contract(a, b, axes)
-        self.note_degradation("contraction_seed")
-        return out
 
     # -------------------------------------------------------------- backends
     def _execute_list(
@@ -599,9 +547,9 @@ class ContractionEngine:
 
         ``retries`` / ``degradations`` are the degradation-ladder ledger
         (DESIGN.md 3.8): stage-keyed counts of failed first attempts and the
-        ladder rung that recovered them (e.g. ``contraction_list``,
-        ``env_seed``, ``pair_seed``).  Both empty on a healthy run — the
-        clean tier-1 bench leg asserts exactly that.
+        ladder rung that recovered them (e.g. ``env_seed``, ``pair_seed``).
+        Both empty on a healthy run — the clean tier-1 bench leg asserts
+        exactly that.
         """
         return {
             "plan_cache": self.cache.stats(),

@@ -86,7 +86,7 @@ FAULT_POINTS: Dict[str, str] = {
     # NaN-poison one bucket output of a batched-GEMM contraction
     # (dist/batch.py execute_batched; skipped under tracing).
     "batch.gemm_nan": "dist/batch.py:execute_batched",
-    # Forced failure of the planned batched jnp.linalg.svd core, standing in
+    # Forced failure of the planned batched SVD core, standing in
     # for LAPACK *gesdd non-convergence (dist/decomp.py svd_split, and the
     # stacked svd_split_multi in serve/multicore.py).
     "decomp.svd_fail": "dist/decomp.py:DecompositionEngine.svd_split",

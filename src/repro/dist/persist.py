@@ -12,9 +12,10 @@ that pipeline across processes:
    ``PlanStore`` maps a canonicalized signature digest to a pickled,
    version-gated entry on disk; the LRU caches consult it on miss and write
    back on build, so a primed store means zero plan builds.
-2. **Compiled executables** via the JAX persistent compilation cache
-   (``jax_compilation_cache_dir``): ``enable_compilation_cache`` points it
-   at ``store.compile_cache_dir`` with the entry-size/compile-time floors
+2. **Compiled executables** via the JAX persistent compilation cache:
+   ``configure_compilation_cache`` turns it on in the one directory the
+   process uses (``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+   ``<checkout>/.jax_cache``), with the entry-size/compile-time floors
    dropped so the many small DMRG cores all qualify.  XLA then skips
    *compilation* of any program it has seen, in any process.
 3. **Traced cores** via ``jax.export``: the padded bucket cores (batched
@@ -34,7 +35,6 @@ Store layout (``PlanStore(root)``)::
       env/<digest>.pkl
       exports/<digest>.pkl       serialized jax.export artifacts, or
                                  refusal tombstones for unexportable cores
-      xla/                       the JAX persistent compilation cache
 
 Every entry is written with the ``core/checkpoint.py`` idiom — mkstemp in
 the target directory, write, flush, fsync, ``os.replace`` — so concurrent
@@ -221,13 +221,6 @@ class PlanStore:
         self._memo: Dict[str, Any] = {}
 
     # ---------------------------------------------------------------- layout
-    @property
-    def compile_cache_dir(self) -> str:
-        """Directory for the JAX persistent compilation cache (created)."""
-        d = os.path.join(self.root, "xla")
-        os.makedirs(d, exist_ok=True)
-        return d
-
     def _plan_path(self, kind: str, sig: Any) -> str:
         assert kind in PLAN_KINDS, kind
         return os.path.join(self.root, kind, signature_digest(sig) + ".pkl")
@@ -567,20 +560,36 @@ class PlanStore:
 _active_store: Optional[PlanStore] = None
 
 
-def enable_compilation_cache(path: str) -> None:
-    """Point the JAX persistent compilation cache at ``path``.
+# the checkout root (src/repro/dist/persist.py -> three levels up from src)
+CHECKOUT_DIR = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(CHECKOUT_DIR, ".jax_cache")
 
-    Drops the min-entry-size and min-compile-time floors so the many small
-    DMRG cores all qualify — without this, jax's defaults (1 second of
-    compile time) would skip exactly the executables whose *count* makes
-    cold starts slow.  Idempotent; safe to call after jax is initialized.
+
+def configure_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory and no other
+    is set.  Otherwise the cache lives at the fixed ``<checkout>/.jax_cache``
+    (git-ignored) — a fixed path, so every process of this checkout shares
+    one cache.  Either
+    way the min-entry-size and min-compile-time floors are dropped so the
+    many small DMRG cores all qualify; with jax's defaults (1 second of
+    compile time) exactly the executables whose *count* makes cold starts
+    slow would be skipped.  Idempotent; safe to call after jax is
+    initialized.
     """
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", os.path.abspath(path))
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILATION_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def activate_store(
@@ -591,8 +600,8 @@ def activate_store(
     Wires it into the three global ``_SignatureLRU`` caches (consulted on
     every miss, written on every build), publishes it to the engines'
     export lookups (``active_store``), and — unless ``compile_cache=False``
-    — enables the JAX persistent compilation cache under
-    ``store.compile_cache_dir``.  ``prefetch`` (default on) kicks off the
+    — turns on the JAX persistent compilation cache
+    (``configure_compilation_cache``).  ``prefetch`` (default on) kicks off the
     background export warm-up (``prefetch_exports``) so first-use lookups
     find ready artifacts; ``prefetch="compile"`` additionally AOT-compiles
     each artifact in the background — the long-lived-worker mode
@@ -607,15 +616,14 @@ def activate_store(
     _active_store = store
     _plan_mod._ACTIVE_STORE = store
     if compile_cache:
-        enable_compilation_cache(store.compile_cache_dir)
+        configure_compilation_cache()
     if prefetch:
         store.prefetch_exports(compile=prefetch == "compile")
     return store
 
 
 def deactivate_store() -> None:
-    """Detach the active store (the compilation-cache dir stays configured:
-    un-configuring it mid-process would orphan live executables' entries)."""
+    """Detach the active store (the compilation cache stays configured)."""
     global _active_store
     _active_store = None
     _plan_mod._ACTIVE_STORE = None

@@ -522,8 +522,8 @@ class SectorSplit:
 class SvdBucket:
     """All sectors sharing one padded matrix shape (Rp, Cp).
 
-    The bucket executes as ONE batched ``jnp.linalg.svd`` over the stacked
-    ``[S, Rp, Cp]`` sector matrices, assembled with a single gather from the
+    The bucket executes as ONE batched SVD (``decomp.bucket_svd``) over the
+    stacked ``[S, Rp, Cp]`` sector matrices, assembled with a single gather from the
     flattened theta blocks (``gather`` indexes into the flat concatenation,
     with the one-past-the-end slot reading the appended zero — structural
     zeros and padding both land there).

@@ -66,7 +66,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .plan import EnvironmentPlan
@@ -159,14 +158,14 @@ def _build_spmd_gemm(mesh: Mesh, row_axis: str, col_axis: str,
         return jax.lax.all_gather(part, col_axis, axis=2, tiled=True)
 
     # the psum + tiled all_gather leave the output replicated, but shard_map
-    # cannot infer that statically -> check_rep=False; equality is pinned by
+    # cannot infer that statically -> check_vma=False; equality is pinned by
     # tests/test_spmd.py instead
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     def fn(lhs, rhs, oi):

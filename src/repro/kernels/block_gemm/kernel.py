@@ -76,8 +76,16 @@ def block_sparse_matmul(
     assert BM % bm == 0 and BN % bn == 0 and BK % bk == 0
     nm, nn, nk = BM // bm, BN // bn, BK // bk
     out_dtype = out_dtype or lhs.dtype
-    # accumulate in f32 on the MXU; promote to f64 only for float64 inputs
-    # (CPU interpret-mode validation — real TPUs have no f64)
+    if not interpret and jnp.finfo(lhs.dtype).bits > 32:
+        # Mosaic refuses 64-bit operands with a bare NotImplementedError
+        raise TypeError(
+            f"block_sparse_matmul: the compiled TPU kernel takes 32-bit or "
+            f"narrower operands, got {jnp.dtype(lhs.dtype).name} (a TPU has "
+            f"no float64 unit); use use_kernel=False for float64 blocks, or "
+            f"interpret=True for CPU validation"
+        )
+    # accumulate in f32 on the MXU; f64 only in interpret mode (CPU
+    # validation of float64 blocks)
     acc_dtype = jnp.float64 if lhs.dtype == jnp.float64 else jnp.float32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the block-sparse GEMM kernel.
 
-Handles pair sorting, MXU-tile padding, and the interpret-mode fallback used
-for CPU validation (this container has no TPU; ``interpret=True`` executes the
-kernel body in Python, per-kernel tests assert allclose vs ``ref.py``).
+Handles pair sorting and MXU-tile padding.  The compiled kernel is a TPU
+target; ``interpret=True``, which CPU tests pass themselves, executes the
+kernel body in Python (per-kernel tests assert allclose vs ``ref.py``).
 """
 from __future__ import annotations
 
@@ -62,6 +62,29 @@ def _kernel_covered(
 
 _ref_jit = jax.jit(block_sparse_matmul_ref, static_argnames=("num_out",))
 
+# The segment-sum's scatter-add stages one [M, N] update window per pair in
+# VMEM.  Above this many elements a v5e can refuse it (a float64 bucket
+# with M*N = 2**20 asked for 20 MiB of scoped VMEM against a 16 MiB limit),
+# so larger products are reduced in row chunks of at most this window.
+SCATTER_WINDOW = 1 << 16
+
+
+def _ref_rows_chunked(lhs, rhs, out_idx, num_out):
+    """``block_sparse_matmul_ref`` with the rows of each product cut into
+    chunks whose scatter window fits ``SCATTER_WINDOW``; a bucket that
+    already fits runs as one call, unchanged."""
+    m, n = lhs.shape[1], rhs.shape[2]
+    rows = max(1, SCATTER_WINDOW // n)
+    if m <= rows:
+        return _ref_jit(lhs, rhs, out_idx, num_out)
+    return jnp.concatenate(
+        [
+            _ref_jit(lhs[:, r:r + rows], rhs, out_idx, num_out)
+            for r in range(0, m, rows)
+        ],
+        axis=1,
+    )
+
 
 def block_sparse_matmul(
     lhs: jax.Array,
@@ -89,7 +112,7 @@ def block_sparse_matmul(
     outputs by construction and skip the check.
     """
     if not use_kernel:
-        return _ref_jit(lhs, rhs, out_idx, num_out)
+        return _ref_rows_chunked(lhs, rhs, out_idx, num_out)
     kw = dict(bm=bm, bn=bn, bk=bk, interpret=interpret)
     if isinstance(out_idx, np.ndarray):
         covered = np.unique(out_idx)

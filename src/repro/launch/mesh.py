@@ -10,13 +10,10 @@ import jax
 
 
 def _make_mesh(shape, axes, devices=None):
-    # axis_types landed after jax 0.4.x; Auto is the default there anyway
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,11 +28,8 @@ def make_mesh(shape, axes, devices=None):
 
 
 def mesh_context(mesh):
-    """Enter a mesh: jax.sharding.set_mesh where available (jax >= 0.5.x),
-    else the legacy global-mesh context manager (``with mesh:``)."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    """Enter a mesh (``jax.sharding.set_mesh``)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 # TPU v5e, per chip (roofline constants from the assignment)

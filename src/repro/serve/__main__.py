@@ -171,6 +171,9 @@ def main(argv=None) -> int:
                          "a zero recovery ledger (no retries/bisections)")
     args = ap.parse_args(argv)
 
+    from repro.dist import configure_compilation_cache
+
+    configure_compilation_cache()
     if args.warmup:
         return run_warmup(args)
 

@@ -325,8 +325,8 @@ def svd_split(
     """Blockwise truncated SVD across a bond (paper Fig. 1e, Sec. IV-A).
 
     Planned front door: delegates to the shape-bucketed batched engine in
-    ``dist/decomp.py`` (one gather-assembled batched ``jnp.linalg.svd`` per
-    padded sector-shape bucket, one host sync per call).  The seed per-sector
+    ``dist/decomp.py`` (one gather-assembled batched SVD per padded
+    sector-shape bucket, one host sync per call).  The seed per-sector
     loop remains available as ``svd_split_unplanned``; the planned path
     matches it to <1e-10 up to the per-singular-vector sign gauge (products
     U·V, singular values, retained sectors and ``trunc_err`` agree

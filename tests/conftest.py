@@ -43,16 +43,15 @@ def _release_compiled_executables():
 
     Interaction with the persistent compilation cache (dist/persist.py):
     ``jax.clear_caches()`` drops only the *in-memory* trace/executable
-    caches — the on-disk cache a ``PlanStore`` activation configured
-    (``jax_compilation_cache_dir``) survives, by design, so post-clear
+    caches — the on-disk cache a ``PlanStore`` activation turned on
+    (``configure_compilation_cache``: ``JAX_COMPILATION_CACHE_DIR`` or the
+    checkout's ``.jax_cache``) survives, by design, so post-clear
     re-compiles of already-seen programs are disk hits rather than full
     XLA compiles.  The disk entries hold no mmaps, so they don't count
     against ``vm.max_map_count``; only re-*loading* them does, and that is
-    exactly the per-module budget this fixture resets.  The cache-dir
-    config itself also survives (deliberately — unsetting it mid-process
-    would orphan live executables' entries), which is why store-activating
-    tests point it at per-test tmp dirs and why the teardown below detaches
-    any store a test module leaked without touching the config.
+    exactly the per-module budget this fixture resets.  The teardown below
+    detaches any store a test module leaked without touching the cache
+    config.
     """
     yield
     import gc

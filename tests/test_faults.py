@@ -24,7 +24,7 @@ from repro.core.mps import neel_states, product_state_mps
 from repro.core.siteops import spin_half_space
 from repro.core.sweep import DMRGEngine
 from repro.dist import faults
-from repro.dist.engine import CONTRACTION_LADDER, ContractionEngine
+from repro.dist.engine import ContractionEngine
 from repro.dist.faults import FaultInjected, FaultRegistry, NumericalHealthError
 from repro.serve import DMRGService, ProblemSpec, StackedOps
 from repro.serve.problems import build_problem
@@ -102,13 +102,26 @@ class TestRegistry:
 
 # ------------------------------------------------- guards + degradation ladder
 class TestDegradationLadder:
-    def test_ladder_ordering(self):
-        """The documented ladder runs fastest-to-safest, ending at the seed,
-        and a failed rung only ever retries rungs BELOW itself."""
-        assert CONTRACTION_LADDER == ("spmd", "csr", "batched", "dense", "list")
-        for i, rung in enumerate(CONTRACTION_LADDER):
-            below = CONTRACTION_LADDER[CONTRACTION_LADDER.index(rung) + 1:]
-            assert below == CONTRACTION_LADDER[i + 1:]
+    @pytest.mark.parametrize("stage", ["env", "decomp"])
+    def test_non_fault_error_propagates(self, stage, monkeypatch):
+        """The ladders catch only injected faults and health errors: any
+        other failure — a compile or lowering error on the chip — reaches
+        the caller instead of a seed-path run that looks healthy."""
+        from repro.dist.decomp import DecompositionEngine
+        from repro.dist.envcore import EnvironmentEngine
+
+        def refuse(*a, **k):
+            raise NotImplementedError("compiler refused the core")
+
+        target = (EnvironmentEngine, "_update") if stage == "env" else (
+            DecompositionEngine, "_execute_planned")
+        eng = _engine()
+        monkeypatch.setattr(*target, refuse)
+        with pytest.raises(NotImplementedError, match="compiler refused"):
+            _two_sweeps(eng)
+        st_ = eng.contract_fn.stats()
+        assert not any(st_["degradations"].values())
+        assert not any(st_["decomp"]["degradations"].values())
 
     def test_clean_run_zero_counters(self):
         eng = _engine(algo="batched", jit_matvec=True)

@@ -55,6 +55,24 @@ class TestBlockGemm:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("window", [8, 24, 1 << 16])
+    def test_row_chunked_segment_sum(self, monkeypatch, window):
+        """Buckets whose scatter window exceeds SCATTER_WINDOW are reduced
+        in row chunks (ragged last chunk included) with the same result."""
+        from repro.kernels.block_gemm import ops
+
+        monkeypatch.setattr(ops, "SCATTER_WINDOW", window)
+        key = jax.random.PRNGKey(1)
+        lhs = jax.random.normal(key, (5, 20, 6), jnp.float32)
+        rhs = jax.random.normal(jax.random.fold_in(key, 1), (5, 6, 4),
+                                jnp.float32)
+        idx = jnp.array([0, 0, 1, 2, 2], jnp.int32)
+        got = bg_op(lhs, rhs, idx, 4, use_kernel=False)
+        want = block_sparse_matmul_ref(lhs, rhs, idx, 4)
+        assert got.shape == (4, 20, 4)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
 
 class TestFlashAttention:
     @given(

@@ -2,6 +2,7 @@
 small in-process mesh (8 host devices via subprocess to avoid polluting the
 test process's device count)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -118,3 +119,20 @@ class TestDryRunSmoke:
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True, timeout=600)
         assert "SMOKE_OK" in r.stdout, r.stderr[-2000:]
+
+
+def test_import_raises_no_deprecation_warning():
+    """Every module of the package imports cleanly on the installed JAX,
+    with deprecation warnings turned into errors."""
+    code = textwrap.dedent("""\
+        import importlib, pkgutil, repro
+        for m in pkgutil.walk_packages(repro.__path__, "repro."):
+            if not m.name.endswith("__main__"):
+                importlib.import_module(m.name)
+        """)
+    proc = subprocess.run(
+        [sys.executable, "-W", "error::DeprecationWarning", "-c", code],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "PYTHONPATH": str(REPO / "src")},
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]

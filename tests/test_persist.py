@@ -75,6 +75,9 @@ def _coldstart_child(store_dir, phase, timeout=900):
     """Run one bench_dist cold-start child (its own process) and parse it."""
     env = dict(os.environ)
     env["JAX_ENABLE_X64"] = "1"
+    # the children's compilation cache lives in the store directory, so a
+    # "cold" child starts with an empty one whatever the checkout holds
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(str(store_dir), "xla")
     proc = subprocess.run(
         [sys.executable, os.path.abspath(BENCH), "--child-coldstart",
          str(store_dir), phase],
@@ -85,6 +88,37 @@ def _coldstart_child(store_dir, phase, timeout=900):
         if line.startswith("BENCH_COLDSTART_JSON "):
             return json.loads(line[len("BENCH_COLDSTART_JSON "):])
     raise AssertionError(proc.stdout)
+
+
+class TestCompilationCacheRule:
+    @pytest.mark.parametrize("env_dir", [True, False])
+    def test_one_directory(self, tmp_path, monkeypatch, env_dir):
+        """``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache
+        is the checkout's fixed ``.jax_cache``.  No other directory."""
+        keys = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs")
+        prev = {k: getattr(jax.config, k) for k in keys}
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            want = str(tmp_path)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                ".jax_cache",
+            )
+        try:
+            assert persist.configure_compilation_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+            store = persist.activate_store(tmp_path / "store", prefetch=False)
+            assert jax.config.jax_compilation_cache_dir == want
+            assert not os.path.exists(os.path.join(store.root, "xla"))
+        finally:
+            persist.deactivate_store()
+            for k, v in prev.items():
+                jax.config.update(k, v)
 
 
 class TestSignatures:
@@ -440,9 +474,10 @@ class TestConcurrentAccess:
 class TestColdStartRegression:
     """The cold-start contract, measured across a real process boundary:
     process A primes the store (and runs the warmup compile pass); process
-    B's first sweep then builds ZERO plans, reproduces A's energy to 1e-10
-    and runs >=5x faster than A's cold first sweep (measured ~10x; the
-    margin absorbs machine noise)."""
+    B's first sweep then builds ZERO plans, replays exported cores, finds
+    its executables in the compilation cache A filled, and reproduces A's
+    energy to 1e-10.  Counts, not wall-clock: a CPU timing ratio says
+    nothing about the chip and does not hold under parallel test workers."""
 
     def test_primed_process_zero_builds_and_speedup(self, tmp_path):
         cold = _coldstart_child(tmp_path, "cold")
@@ -451,8 +486,5 @@ class TestColdStartRegression:
         assert abs(cold["energy"] - primed["energy"]) < 1e-10
         assert cold["store"]["saves"] > 0 and cold["store"]["export_saves"] > 0
         assert primed["store"]["hits"] > 0
-        speedup = cold["first_s"] / max(primed["first_s"], 1e-9)
-        assert speedup >= 5.0, (
-            f"primed first sweep only {speedup:.1f}x faster than cold "
-            f"({cold['first_s']:.2f}s -> {primed['first_s']:.2f}s)"
-        )
+        assert primed["store"]["export_hits"] > 0, primed
+        assert primed["compile_cache_hits"] > 0, primed

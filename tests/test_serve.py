@@ -247,10 +247,11 @@ class TestService:
     @pytest.mark.slow
     def test_end_to_end_correct_energies(self, tmp_path):
         """Full service path — queue, worker thread, warmed zero-retrace
-        steady state, energies vs independent singles — in its OWN process:
-        on jaxlib 0.4.x, XLA compilation with a live secondary thread can
-        segfault late in a large shared pytest process (it is rock-solid in
-        a fresh interpreter, which is also how the serve CLI runs)."""
+        steady state, energies vs independent singles — in its OWN process,
+        the way the serve CLI runs: a fresh interpreter in which only the
+        caller and the service worker compile (jaxlib 0.4.x segfaulted on
+        XLA compilation from a secondary thread late in a large shared
+        pytest process; the installed JAX is 0.9)."""
         src = os.path.join(os.path.dirname(__file__), "..", "src")
         code = textwrap.dedent(f"""\
         import os
