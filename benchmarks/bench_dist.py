@@ -366,7 +366,6 @@ def _bench(n=16, m=32, quick=False):
         eng = fresh_engine(algo="batched")
         _, t_be, e_be, _, _, _ = timed_sweeps(eng)
         rec["batched_eager_sweep_s"] = t_be
-        rec["batched_eager_stats"] = eng.contract_fn.stats()["backend_seconds"]
 
         _, t_auto, e_auto, _, _, _ = timed_sweeps(fresh_engine(algo="auto"))
         rec["auto_sweep_s"] = t_auto

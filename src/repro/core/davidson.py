@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..dist import faults
 from ..dist.faults import NumericalHealthError
 from ..tensor.blocksparse import BlockSparseTensor
@@ -63,7 +64,21 @@ def _new_columns(V, AV, i) -> np.ndarray:
     """Fetch M[j, i] and W[j, i] for j <= i in one device round-trip."""
     vals = [V[j].inner(AV[i]) for j in range(i + 1)]
     vals += [AV[j].inner(AV[i]) for j in range(i + 1)]
-    return np.real(np.asarray(jax.device_get(jnp.stack(vals))))
+    stacked = jnp.stack(vals)
+    with obs.span("davidson.read"):
+        return np.real(np.asarray(jax.device_get(stacked)))
+
+
+def _read_norm(t: BlockSparseTensor) -> float:
+    """``t.norm()`` on the host: a blocking device read."""
+    nrm = t.norm()
+    with obs.span("davidson.read"):
+        return float(np.asarray(nrm))
+
+
+def _apply(matvec, x: BlockSparseTensor) -> BlockSparseTensor:
+    with obs.span("davidson.matvec"):
+        return matvec(x)
 
 
 def davidson(
@@ -81,6 +96,11 @@ def davidson(
     into the eigh and out through the MPS, so it raises
     ``NumericalHealthError(stage="davidson")`` at zero extra sync cost.
     """
+    with obs.span("davidson.solve"):
+        return _davidson(matvec, x0, n_iter, tol, seed)
+
+
+def _davidson(matvec, x0, n_iter, tol, seed):
     info = DavidsonInfo()
     # injected non-convergence: suppress the residual break so the solve
     # runs its full budget and honestly reports converged=False
@@ -88,9 +108,11 @@ def davidson(
     nrm = x0.norm()
     x = x0.scale(1.0 / nrm)
     V = [x]
-    AV = [matvec(x)]
+    AV = [_apply(matvec, x)]
     if n_iter <= 0:
-        lam = float(np.real(np.asarray(V[0].inner(AV[0]))))
+        rayleigh = V[0].inner(AV[0])
+        with obs.span("davidson.read"):
+            lam = float(np.real(np.asarray(rayleigh)))
         if not np.isfinite(lam):
             raise NumericalHealthError(
                 "non-finite Rayleigh quotient", stage="davidson"
@@ -134,7 +156,7 @@ def davidson(
         if qn2_gram > noise_floor:
             qn = float(np.sqrt(qn2_gram))
         else:
-            qn = float(np.asarray(q.norm()))
+            qn = _read_norm(q)
         if qn < tol and not force_no_converge:
             info.converged = True
             break
@@ -142,24 +164,24 @@ def davidson(
         # modified Gram-Schmidt vs all v_j, randomize on breakdown (paper)
         for j in range(i + 1):
             q = q - V[j].scale(V[j].inner(q))
-        qn2 = float(np.asarray(q.norm()))
+        qn2 = _read_norm(q)
         if qn2 < GS_BREAKDOWN_TOL * max(qn, 1.0):
             # restart with A·(random): confined to range(A), so under the
             # bucket-padded matvec (dist/batch.py) the new direction stays
             # in the invariant unpadded subspace instead of acquiring O(1)
             # weight in the padded rows where the operator is zero
             info.restarts += 1
-            q = matvec(BlockSparseTensor.random(
+            q = _apply(matvec, BlockSparseTensor.random(
                 x.indices, x.charge, jax.random.PRNGKey(seed + i), dtype=x.dtype
             ))
             for j in range(i + 1):
                 q = q - V[j].scale(V[j].inner(q))
-            qn2 = float(np.asarray(q.norm()))
+            qn2 = _read_norm(q)
             if qn2 < GS_BREAKDOWN_TOL * max(qn, 1.0):
                 info.exhausted = True
                 break  # subspace exhausted; accept the current Ritz pair
         q = q.scale(1.0 / qn2)
         V.append(q)
-        AV.append(matvec(q))
+        AV.append(_apply(matvec, q))
 
     return lam, x.scale(1.0 / x.norm()), info

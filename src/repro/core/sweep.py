@@ -31,6 +31,11 @@ rebuilds the right environments as one planned right-to-left pass.
 ``jit_env=False`` (or a bare contractor) falls back to the seed
 ``extend_left`` / ``extend_right``; ``SweepStats.env_seconds`` carries the
 stage's wall-clock per sweep.
+
+Under a JAX profiler session each pair update is a ``sweep.pair`` span of
+``repro.obs`` with its stages nested inside: ``sweep.theta``, ``sweep.pad``,
+``sweep.operator``, ``davidson.solve``, ``sweep.unpad``, ``split``,
+``sweep.place`` and ``env.update``.
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional
 
+from .. import obs
 from ..dist import faults
 from ..dist.batch import pad_block_sparse, unpad_block_sparse
 from ..dist.engine import ContractionEngine
@@ -78,7 +84,8 @@ class SweepStats:
     # wall-clock of the environment stage (all left/right env updates) this
     # sweep, in seconds — fused jitted updates when ``jit_env`` is on, the
     # seed three-contraction path otherwise.  Host-side dispatch time (jax
-    # is async), like the contraction engine's ``backend_seconds``.
+    # is async); the ``env.update`` spans of ``repro.obs`` time the same
+    # stage per update under a profiler session.
     env_seconds: float = 0.0
     # Davidson health ledger for the sweep (core/davidson.py DavidsonInfo):
     # solves run, solves whose residual actually converged below tol (budget-
@@ -315,7 +322,8 @@ class DMRGEngine:
     ):
         T, W = self.mps.tensors, self.mpo
         A, B = self.left_envs[j], self.right_envs[j + 1]
-        theta = self.contract_fn(T[j], T[j + 1], ((2,), (0,)))
+        with obs.span("sweep.theta"):
+            theta = self.contract_fn(T[j], T[j + 1], ((2,), (0,)))
 
         pad = (
             self.pad_matvec and isinstance(self.contract_fn, ContractionEngine)
@@ -325,17 +333,19 @@ class DMRGEngine:
             # exact (padded operator entries are zero) and quantizes the
             # traced structure, so the jitted matvec compiles once per
             # bucketed structure instead of once per site per sweep
-            orig_indices = theta.indices
-            A, B = pad_block_sparse(A), pad_block_sparse(B)
-            Wjp, Wj1p = self._padded_mpo(j), self._padded_mpo(j + 1)
-            theta = pad_block_sparse(theta)
+            with obs.span("sweep.pad"):
+                orig_indices = theta.indices
+                A, B = pad_block_sparse(A), pad_block_sparse(B)
+                Wjp, Wj1p = self._padded_mpo(j), self._padded_mpo(j + 1)
+                theta = pad_block_sparse(theta)
         else:
             Wjp, Wj1p = W[j], W[j + 1]
 
         if isinstance(self.contract_fn, ContractionEngine):
-            mv = self.contract_fn.matvec_fn(
-                A, Wjp, Wj1p, B, jit=self.jit_matvec
-            )
+            with obs.span("sweep.operator"):
+                mv = self.contract_fn.matvec_fn(
+                    A, Wjp, Wj1p, B, jit=self.jit_matvec
+                )
         else:
             def mv(x):
                 return matvec_two_site(A, Wjp, Wj1p, B, x, self.contract_fn)
@@ -344,7 +354,8 @@ class DMRGEngine:
             mv, theta, n_iter=self.davidson_iters, seed=self.seed + j
         )
         if pad:
-            theta = unpad_block_sparse(theta, orig_indices)
+            with obs.span("sweep.unpad"):
+                theta = unpad_block_sparse(theta, orig_indices)
         # decomposition stage: planned engines stay in device-land — one
         # batched SVD core call plus a single singular-value sync for the
         # global truncation — while the seed path loops sectors on host
@@ -358,8 +369,9 @@ class DMRGEngine:
                 theta, 2, max_bond=max_bond, cutoff=cutoff, absorb=absorb
             )
         svd_dt = time.perf_counter() - t_svd
-        T[j] = self._place(flip_flow(U, 2))
-        T[j + 1] = self._place(flip_flow(V, 0))
+        with obs.span("sweep.place"):
+            T[j] = self._place(flip_flow(U, 2))
+            T[j + 1] = self._place(flip_flow(V, 0))
         return lam, err, svd_dt, dinfo
 
     def sweep(
@@ -401,17 +413,21 @@ class DMRGEngine:
             before = 0
             if isinstance(self.contract_fn, ContractionEngine):
                 before = self.contract_fn.retries.get("pair", 0)
-            lam, err, svd_dt, dinfo = self._optimize_pair(
-                j, max_bond, cutoff, absorb=absorb
-            )
+            with obs.span("sweep.pair"):
+                lam, err, svd_dt, dinfo = self._optimize_pair(
+                    j, max_bond, cutoff, absorb=absorb
+                )
+                te = time.perf_counter()
+                with obs.span("env.update"):
+                    if absorb == "right":
+                        self.left_envs[j + 1] = self._place(
+                            self._extend_left_env(j))
+                    else:
+                        self.right_envs[j] = self._place(
+                            self._extend_right_env(j))
+                env_secs += time.perf_counter() - te
             if isinstance(self.contract_fn, ContractionEngine):
                 pair_retries += self.contract_fn.retries.get("pair", 0) - before
-            te = time.perf_counter()
-            if absorb == "right":
-                self.left_envs[j + 1] = self._place(self._extend_left_env(j))
-            else:
-                self.right_envs[j] = self._place(self._extend_right_env(j))
-            env_secs += time.perf_counter() - te
             energies.append(lam)
             site_secs.append(time.perf_counter() - ts)
             max_err = max(max_err, err)

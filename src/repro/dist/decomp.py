@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..tensor.blocksparse import BlockSparseTensor
 from ..tensor.qn import IN, Index, OUT, qzero
 from . import faults, persist
@@ -85,7 +86,7 @@ _host_svd = {"calls": 0, "seconds": 0.0}
 def host_svd_stats() -> Dict:
     """Process-wide ledger of the float64 host LAPACK callback: ``calls``
     and ``seconds`` of host wall-clock spent inside it (the decomposition
-    stage's host-resident share; the rest of ``svd_seconds`` is device
+    stage's host-resident share; the rest of a ``split`` span is device
     gather/absorb work and the truncation sync)."""
     with _host_svd_lock:
         return dict(_host_svd)
@@ -97,17 +98,21 @@ def _host_lapack_svd(x: np.ndarray):
     Non-convergence (or non-finite input) fills the outputs with NaN, the
     same contract as XLA's own LAPACK lowering, so the numerical-health
     guard at the truncation sync sees it instead of a callback exception.
+    It runs on the runtime's callback thread, so it is a profiler annotation
+    (``split.lapack``) and not a ``repro.obs`` span.
     """
     t0 = time.perf_counter()
-    x = np.asarray(x)
-    try:
-        u, s, vh = np.linalg.svd(x, full_matrices=False)
-    except np.linalg.LinAlgError:
-        k = min(x.shape[-2:])
-        u = np.full(x.shape[:-1] + (k,), np.nan, x.dtype)
-        s = np.full(x.shape[:-2] + (k,), np.nan, np.finfo(x.dtype).dtype)
-        vh = np.full(x.shape[:-2] + (k, x.shape[-1]), np.nan, x.dtype)
-    out = u.astype(x.dtype), s.astype(np.finfo(x.dtype).dtype), vh.astype(x.dtype)
+    with jax.profiler.TraceAnnotation("split.lapack"):
+        x = np.asarray(x)
+        try:
+            u, s, vh = np.linalg.svd(x, full_matrices=False)
+        except np.linalg.LinAlgError:
+            k = min(x.shape[-2:])
+            u = np.full(x.shape[:-1] + (k,), np.nan, x.dtype)
+            s = np.full(x.shape[:-2] + (k,), np.nan, np.finfo(x.dtype).dtype)
+            vh = np.full(x.shape[:-2] + (k, x.shape[-1]), np.nan, x.dtype)
+        out = (u.astype(x.dtype), s.astype(np.finfo(x.dtype).dtype),
+               vh.astype(x.dtype))
     with _host_svd_lock:
         _host_svd["calls"] += 1
         _host_svd["seconds"] += time.perf_counter() - t0
@@ -341,7 +346,6 @@ class DecompositionEngine:
         self.rsvd_seed = rsvd_seed
         self.svd_calls = 0
         self.svd_flops = 0.0
-        self.svd_seconds = 0.0
         self.jit_retraces = 0
         self.sectors_processed = 0
         self.buckets_processed = 0
@@ -407,11 +411,11 @@ class DecompositionEngine:
         if not self.jit:
             return body
 
-        def traced(blocks):
+        def svd_core(blocks):
             engine.jit_retraces += 1  # body runs only when jax (re)traces
             return body(blocks)
 
-        return jax.jit(traced)
+        return jax.jit(svd_core)
 
     def _build_slice_core(self, plan: DecompositionPlan, m_q: Tuple[int, ...]):
         """Compile (or wrap eagerly) the shared ``slice_core_body``.
@@ -428,11 +432,11 @@ class DecompositionEngine:
         if not self.jit:
             return body
 
-        def traced(bucket_out):
+        def slice_core(bucket_out):
             engine.jit_retraces += 1
             return body(bucket_out)
 
-        return jax.jit(traced)
+        return jax.jit(slice_core)
 
     # ----------------------------------------------------------------- entry
     def svd_split(
@@ -467,8 +471,7 @@ class DecompositionEngine:
                 "svd_split needs concrete blocks: the global truncation syncs "
                 "singular values to host, so it cannot run under jit tracing"
             )
-        t0 = time.perf_counter()
-        try:
+        with obs.span("split"):
             plan = self.cache.get(theta, n_row_modes)
             methods, sketch = self._bucket_methods(plan, int(max_bond))
             try:
@@ -509,8 +512,6 @@ class DecompositionEngine:
                         stage="svd",
                     )
                 return U_t, V_t, svals, trunc_err
-        finally:
-            self.svd_seconds += time.perf_counter() - t0
 
     def _execute_planned(
         self, plan, theta, max_bond, cutoff, absorb, methods, sketch
@@ -524,7 +525,36 @@ class DecompositionEngine:
             self.rsvd_power_iters,
             self.rsvd_seed,
         )
-        blocks_in = tuple(theta.blocks[k] for k in plan.block_order)
+        with obs.span("split.core"):
+            blocks_in = tuple(theta.blocks[k] for k in plan.block_order)
+            core = self._svd_core(plan, key, methods, sketch, blocks_in)
+            bucket_out, s_cat = core(blocks_in)
+
+        self.svd_calls += 1
+        self.svd_flops += self._call_flops(plan, methods, sketch)
+        self.sectors_processed += plan.num_sectors
+        self.buckets_processed += plan.num_buckets
+        self.rsvd_buckets += sum(1 for m in methods if m == "rsvd")
+
+        # ---- the one host sync: all singular values, already masked.  The
+        # numerical-health guard rides this existing sync (zero extra device
+        # round-trips): non-finite values here mean the SVD input or the
+        # decomposition itself went bad, and must not reach the MPS.
+        with obs.span("split.read"):
+            s_host = np.asarray(jax.device_get(s_cat))
+        if not np.isfinite(s_host).all():
+            raise NumericalHealthError(
+                "non-finite singular values at the truncation sync",
+                stage="svd",
+            )
+        with obs.span("split.slice"):
+            return self._truncate_and_slice(
+                plan, key, theta, bucket_out, s_host, max_bond, cutoff
+            )
+
+    def _svd_core(self, plan, key, methods, sketch, blocks_in):
+        """The plan's cached assembly + SVD core for ``key``, built (or
+        loaded from the export store) on first use."""
         core = plan._exec.get(key)
         if core is None:
             # export round-trip (dist/persist.py): a primed store replays the
@@ -547,24 +577,13 @@ class DecompositionEngine:
                         (blocks_in,),
                     )
             _cache_exec(plan, key, core)
-        bucket_out, s_cat = core(blocks_in)
+        return core
 
-        self.svd_calls += 1
-        self.svd_flops += self._call_flops(plan, methods, sketch)
-        self.sectors_processed += plan.num_sectors
-        self.buckets_processed += plan.num_buckets
-        self.rsvd_buckets += sum(1 for m in methods if m == "rsvd")
-
-        # ---- the one host sync: all singular values, already masked.  The
-        # numerical-health guard rides this existing sync (zero extra device
-        # round-trips): non-finite values here mean the SVD input or the
-        # decomposition itself went bad, and must not reach the MPS.
-        s_host = np.asarray(jax.device_get(s_cat))
-        if not np.isfinite(s_host).all():
-            raise NumericalHealthError(
-                "non-finite singular values at the truncation sync",
-                stage="svd",
-            )
+    def _truncate_and_slice(
+        self, plan, key, theta, bucket_out, s_host, max_bond, cutoff
+    ):
+        """Global truncation on the synced singular values, then the
+        retained U columns / V rows as output blocks."""
         k_out = [int(out[1].shape[-1]) for out in bucket_out]
         # global truncation, deterministic tie-break (sector, position)
         m_q, trunc_err = host_truncate(plan, s_host, k_out, max_bond, cutoff)
@@ -629,10 +648,6 @@ class DecompositionEngine:
           (LAPACK-gesdd-style counts for exact buckets, sketch+power-GEMM
           counts for randomized ones) — a cost-model estimate, not a
           hardware counter.
-        - ``svd_seconds``: host wall-clock per call, *including* the
-          singular-value device sync — unlike the contraction engine's
-          ``backend_seconds`` this reflects actual device compute, because
-          the sync blocks on the batched SVDs.
         - ``jit_retraces``: times the compiled cores (batched-SVD core and
           output-slice core) were (re)traced; at structural steady state
           this stops growing (compile-once).  Cores are cached on the plan
@@ -651,7 +666,6 @@ class DecompositionEngine:
             "plan_cache": self.cache.stats(),
             "svd_calls": self.svd_calls,
             "svd_flops": self.svd_flops,
-            "svd_seconds": self.svd_seconds,
             "jit_retraces": self.jit_retraces,
             "sectors": self.sectors_processed,
             "buckets": self.buckets_processed,

@@ -30,7 +30,6 @@ bond dimensions reuse both the plans and the compiled matvec.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Dict, Optional
 
 import jax
@@ -101,7 +100,6 @@ class ContractionEngine:
         zero = {"list": 0, "dense": 0, "csr": 0, "batched": 0, "spmd": 0}
         self.backend_counts: Dict[str, int] = dict(zero)
         self.backend_flops: Dict[str, float] = {k: 0.0 for k in zero}
-        self.backend_seconds: Dict[str, float] = {k: 0.0 for k in zero}
         self.jit_retraces = 0
         self._jit_mv = None
         # loaded/attempted matvec exports keyed by (conf, operand, x)
@@ -152,14 +150,12 @@ class ContractionEngine:
             and not (_is_tracing(a) or _is_tracing(b))
         ):
             a, b = self.policy.replicated(a), self.policy.replicated(b)
-        t0 = time.perf_counter()
         if backend in ("batched", "spmd"):
             out = getattr(self, f"_execute_{backend}")(
                 plan, a, b, a_mats=a_mats, b_mats=b_mats
             )
         else:
             out = getattr(self, f"_execute_{backend}")(plan, a, b)
-        self.backend_seconds[backend] += time.perf_counter() - t0
         # spmd mode constrains output layout; storage mode leaves compute
         # results replicated — the sweep re-places what it actually stores
         if (
@@ -362,11 +358,11 @@ class ContractionEngine:
             return lambda x: self.two_site_matvec(A, Wj, Wj1, B, x, mats=mats)
         if self._jit_mv is None:
 
-            def _traced(A_, Wj_, Wj1_, B_, mats_, x_):
+            def matvec_core(A_, Wj_, Wj1_, B_, mats_, x_):
                 self.jit_retraces += 1  # body runs only when jax (re)traces
                 return self.two_site_matvec(A_, Wj_, Wj1_, B_, x_, mats=mats_)
 
-            self._jit_mv = jax.jit(_traced)
+            self._jit_mv = jax.jit(matvec_core)
         store = persist.active_store()
         if store is None or self.policy is not None:
             # no store (or mesh-placed operands, whose shardings must not be
@@ -528,22 +524,19 @@ class ContractionEngine:
 
     # ------------------------------------------------------------- reporting
     def stats(self) -> Dict:
-        """Plan-cache, backend-dispatch, flop, wall-time and retrace counters.
+        """Plan-cache, backend-dispatch, flop and retrace counters.
 
         ``backend_counts`` / ``backend_flops`` increment when ``__call__``
         runs, i.e. at trace time under a jitted matvec — compiled replays
         bypass Python, so with ``jit_matvec=True`` they reflect unique traced
-        structures, not total executed contractions.  ``backend_seconds`` is
-        host-side dispatch time in seconds (jax is async; it excludes device
-        queue drain, and under tracing it measures trace time).
+        structures, not total executed contractions.
         ``jit_retraces`` counts how many times the jitted matvec was
         (re)traced — the compile-time side of the ledger, vs steady-state
         replays.  ``decomp`` is the decomposition-stage sub-ledger (SVD
-        calls/flops/seconds/retraces; see ``DecompositionEngine.stats``) and
-        ``env`` the environment-stage one (fused update count/flops/wall/
-        retraces; see ``EnvironmentEngine.stats``) — together with the
-        contraction counters they give the per-stage split that
-        ``benchmarks/bench_dist.py`` reports.
+        calls/flops/retraces; see ``DecompositionEngine.stats``) and ``env``
+        the environment-stage one (fused update count/flops/retraces; see
+        ``EnvironmentEngine.stats``).  Stage times come from the spans of
+        ``repro.obs`` under a profiler session.
 
         ``retries`` / ``degradations`` are the degradation-ladder ledger
         (DESIGN.md 3.8): stage-keyed counts of failed first attempts and the
@@ -555,7 +548,6 @@ class ContractionEngine:
             "plan_cache": self.cache.stats(),
             "backend_counts": dict(self.backend_counts),
             "backend_flops": dict(self.backend_flops),
-            "backend_seconds": dict(self.backend_seconds),
             "jit_retraces": self.jit_retraces,
             "retries": dict(self.retries),
             "degradations": dict(self.degradations),

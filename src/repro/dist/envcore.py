@@ -37,7 +37,6 @@ energies with ``jit_env=True`` equal seed to <1e-10).
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -131,7 +130,6 @@ class EnvironmentEngine:
         self.pad = pad
         self.env_updates = 0
         self.env_flops = 0.0
-        self.env_seconds = 0.0
         self.jit_retraces = 0
 
     # ------------------------------------------------------------- jit core
@@ -148,11 +146,11 @@ class EnvironmentEngine:
         if not self.jit:
             return body
 
-        def traced(env_blocks, site_blocks, mpo_blocks):
+        def env_core(env_blocks, site_blocks, mpo_blocks):
             engine.jit_retraces += 1  # body runs only when jax (re)traces
             return body(env_blocks, site_blocks, mpo_blocks)
 
-        return jax.jit(traced)
+        return jax.jit(env_core)
 
     # ----------------------------------------------------------------- entry
     def update_left(
@@ -192,7 +190,6 @@ class EnvironmentEngine:
         # any work so the caller's seed-extend fallback sees a clean slate.
         if faults.fire("env.exception") is not None:
             raise FaultInjected("env.exception", "fused env core failed")
-        t0 = time.perf_counter()
         if self.pad:
             # the MPO is immutable for a run, so callers (the sweep) may pass
             # its padded form once instead of re-padding every site visit
@@ -243,7 +240,6 @@ class EnvironmentEngine:
                 out = unpad_block_sparse(out, env_out_indices(T, W, side))
             self.env_updates += 1
             self.env_flops += plan.flops
-            self.env_seconds += time.perf_counter() - t0
             return out
         store = persist.active_store() if self.jit and not tracing else None
         if store is not None:
@@ -270,7 +266,6 @@ class EnvironmentEngine:
             out = unpad_block_sparse(out, env_out_indices(T, W, side))
         self.env_updates += 1
         self.env_flops += plan.flops
-        self.env_seconds += time.perf_counter() - t0
         return out
 
     # ------------------------------------------------------------- reporting
@@ -282,10 +277,6 @@ class EnvironmentEngine:
         - ``env_flops``: summed pair-table flops of the executed plans —
           counted on the *padded* structure (what actually runs), a
           cost-model estimate, not a hardware counter.
-        - ``env_seconds``: host wall-clock per update (pad + plan lookup +
-          fused-call dispatch + unpad).  Jax is async, so like the
-          contraction engine's ``backend_seconds`` this excludes device
-          queue drain.
         - ``jit_retraces``: times the fused core was (re)traced; with
           padding on, this stops growing at structural steady state
           (compile-once).  Cores are cached on the globally shared plan, so
@@ -295,7 +286,6 @@ class EnvironmentEngine:
             "plan_cache": self.cache.stats(),
             "env_updates": self.env_updates,
             "env_flops": self.env_flops,
-            "env_seconds": self.env_seconds,
             "jit_retraces": self.jit_retraces,
         }
 

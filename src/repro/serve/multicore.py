@@ -364,12 +364,12 @@ def _wrap_multi(body, ops: Optional[StackedOps]):
     engines a trace is attributed to the ops instance that first compiled it.
     """
 
-    def traced(*args):
+    def multi_core(*args):
         if ops is not None:
             ops.retraces += 1
         return body(*args)
 
-    return jax.jit(jax.vmap(traced))
+    return jax.jit(jax.vmap(multi_core))
 
 
 # -------------------------------------------------------------------- engine

@@ -210,11 +210,11 @@ class StackedOps:
         if fn is None:
             ops = self
 
-            def traced(*args):
+            def stacked_core(*args):
                 ops.retraces += 1  # body runs only when jax (re)traces
                 return body(*args)
 
-            fn = jax.jit(jax.vmap(traced))
+            fn = jax.jit(jax.vmap(stacked_core))
             self._fns[key] = fn
         return fn
 

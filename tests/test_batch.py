@@ -180,7 +180,6 @@ class TestEngineStats:
         st_ = eng.stats()
         assert st_["backend_counts"]["batched"] == 1
         assert st_["backend_flops"]["batched"] > 0
-        assert st_["backend_seconds"]["batched"] > 0
         assert st_["jit_retraces"] == 0
         assert st_["backend_counts"]["list"] == 0
 

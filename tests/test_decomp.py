@@ -309,7 +309,7 @@ class TestEngineIntegration:
         U, V, _, _ = eng.svd_split(theta, 2, max_bond=8)
         st_ = eng.stats()["decomp"]
         assert st_["svd_calls"] == 1
-        assert st_["svd_flops"] > 0 and st_["svd_seconds"] > 0
+        assert st_["svd_flops"] > 0
         assert st_["sectors"] >= st_["buckets"] >= 1
         assert st_["plan_cache"]["misses"] == 1
 
