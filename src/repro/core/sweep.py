@@ -350,8 +350,11 @@ class DMRGEngine:
             def mv(x):
                 return matvec_two_site(A, Wjp, Wj1p, B, x, self.contract_fn)
 
+        # padded operands bound the block structures, so the subspace
+        # algebra runs as compiled programs too (core/davidson.py)
         lam, theta, dinfo = davidson(
-            mv, theta, n_iter=self.davidson_iters, seed=self.seed + j
+            mv, theta, n_iter=self.davidson_iters, seed=self.seed + j,
+            fused=pad,
         )
         if pad:
             with obs.span("sweep.unpad"):

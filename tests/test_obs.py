@@ -3,11 +3,13 @@ allocated without a profiler session; under one, one ``sweep.pair`` per pair
 update with its stages nested inside, the same spans in the profile, every
 blocking host read of a pair update inside a ``*.read`` span; and the
 jitted cores under stable names."""
+import importlib
 import statistics
 import sys
 import tracemalloc
 
 import jax
+import numpy as np
 import pytest
 from jax._src.array import ArrayImpl
 from jax._src.lib import _profiler
@@ -21,6 +23,8 @@ from repro.core.sweep import DMRGEngine
 from repro.dist import pad_block_sparse
 from repro.dist.decomp import _host_lapack_svd
 
+# the module: ``repro.core.davidson`` names the function re-exported there
+dav = importlib.import_module("repro.core.davidson")
 N_SITES, MAX_BOND = 6, 8  # the 2x3 cylinder at its exact bond
 # the spans directly under ``sweep.pair`` on the production path
 STAGES = ("sweep.theta", "sweep.pad", "sweep.operator", "davidson.solve",
@@ -143,9 +147,13 @@ def test_one_pair_span_per_update_with_its_stages(traced):
             assert p_start <= start and end <= p_end
             if p_name == "sweep.pair":
                 assert name in STAGES
+            if name == "davidson.fused":
+                assert p_name == "davidson.solve"
     for r in roots:
         assert {k: v[1] for k, v in r["spans"].items() if k in STAGES} == \
             dict.fromkeys(STAGES, 1)
+        # the padded operands run the subspace algebra as fused programs
+        assert r["spans"]["davidson.fused"][1] >= 1
 
 
 def test_stage_spans_cover_the_pair_update(traced):
@@ -231,6 +239,16 @@ def test_jitted_cores_have_stable_names(engine):
         tuple(site.blocks[k] for k in eplan.site_keys),
         tuple(W.blocks[k] for k in eplan.mpo_keys))
 
+    x = pad_block_sparse(theta)
+    _, V, AV = dav._start(x, 3)
+    lowered["davidson_start"] = dav._start.lower(x, 3)
+    lowered["davidson_columns"] = dav._columns.lower(V, AV, x, 0)
+    lowered["davidson_ritz"] = dav._ritz.lower(V, AV, np.ones(3), -1.0)
+    lowered["davidson_orthogonalize"] = dav._orthogonalize.lower(V, x, 0)
+
     for stage, name in (("matvec", "matvec_core"), ("svd", "svd_core"),
-                        ("slice", "slice_core"), ("env", "env_core")):
+                        ("slice", "slice_core"), ("env", "env_core"),
+                        *((n, n) for n in ("davidson_start", "davidson_columns",
+                                           "davidson_ritz",
+                                           "davidson_orthogonalize"))):
         assert f"module @jit_{name} " in lowered[stage].as_text(), stage
